@@ -20,6 +20,9 @@ import jax
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced step counts (CI)")

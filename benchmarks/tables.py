@@ -484,13 +484,14 @@ import jax
 import jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.data.synthetic import MarkovLM
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainHyper, make_train_step
 out = {}
 for mode in ("allreduce", "broadcast"):
     cfg = get_config("llama3-8b", reduced=True)
     hyper = TrainHyper(lr=0.05, rank=2, q_chunk=64, warmup_steps=20,
                        remat=False, sync_mode=mode)
-    mesh = jax.make_mesh((4, 1), ("data", "model"))
+    mesh = make_mesh((4, 1), ("data", "model"))
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     data = MarkovLM(vocab=cfg.vocab_size, seed=0)
     with jax.set_mesh(mesh):
@@ -544,8 +545,9 @@ def sync_mode_profile(params, specs, workers: int = 16) -> list:
         if line.startswith("SYNC_MEASURE_JSON="):
             measured = json.loads(line.split("=", 1)[1])
     if not measured:
-        print(f"sync_mode_profile: mesh measurement failed\n{proc.stderr}",
-              file=_sys.stderr)
+        raise RuntimeError(
+            f"sync_mode_profile: mesh measurement failed "
+            f"(rc {proc.returncode})\n{proc.stderr}")
 
     key = jax.random.key(0)
     shapes = jax.tree_util.tree_map(

@@ -28,6 +28,7 @@ from repro.configs.base import LayerSlot, ModelConfig
 from repro.core.compressors import IdentityCompressor, PowerSGDCompressor
 from repro.data.synthetic import MarkovLM
 from repro.launch.train import TrainHyper, make_train_step
+from repro.launch.mesh import make_mesh
 
 
 PRESETS = {
@@ -90,8 +91,7 @@ def main():
 
     cfg = PRESETS[args.preset]
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((max(1, n_dev // 2), min(2, n_dev)),
-                         ("data", "model"))
+    mesh = make_mesh((max(1, n_dev // 2), min(2, n_dev)), ("data", "model"))
     print(f"model: {cfg.name}  params≈{cfg.param_count()/1e6:.1f}M  "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
 

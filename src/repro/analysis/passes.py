@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Set, Tuple
 
-import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding
@@ -140,7 +140,7 @@ def _collect_pack_slice(jaxpr, wire_vars: Set) -> Tuple[List, Set]:
             continue
         sliced.append(eqn)
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jax_core.Literal):
                 on_path.add(v)
     return sliced, on_path
 
@@ -170,7 +170,7 @@ def check_wire_dtypes(artifact: TraceArtifact,
         if name not in ("psum", "all_gather"):
             continue
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jax_core.Literal):
                 continue
             aval = v.aval
             if name == "psum":

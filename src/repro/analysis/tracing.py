@@ -58,7 +58,11 @@ def _eqn_axes(eqn) -> Tuple[str, ...]:
 def provenance_chain(eqn, package: str = "/repro/") -> Tuple[Tuple[str, str, int], ...]:
     """(file, function, line) frames of the eqn's traceback that live inside
     ``package``, innermost first.  Empty when the collective was issued
-    outside the repro tree (a hand-rolled collective — GL103)."""
+    outside the repro tree (a hand-rolled collective — GL103).
+
+    ``function`` is the bare name: tracebacks report the qualified name
+    (``MeshCtx.pmean_flat.<locals>.issue``), and the attribution contract
+    (:data:`repro.core.dist.COLLECTIVE_SITES`) is keyed by the last part."""
     src = getattr(eqn, "source_info", None)
     tb = getattr(src, "traceback", None)
     if tb is None:
@@ -67,7 +71,8 @@ def provenance_chain(eqn, package: str = "/repro/") -> Tuple[Tuple[str, str, int
     for fr in tb.frames:
         if package in fr.file_name.replace("\\", "/"):
             name = fr.file_name.replace("\\", "/").rsplit(package, 1)[-1]
-            chain.append((name, fr.function_name, fr.line_num))
+            func = fr.function_name.rsplit(".", 1)[-1]
+            chain.append((name, func, fr.line_num))
     return tuple(chain)
 
 

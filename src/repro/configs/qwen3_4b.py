@@ -1,4 +1,4 @@
-"""Qwen3-4B — dense GQA with qk-norm [hf:Qwen/Qwen3-8B family]."""
+"""Qwen3-4B — dense GQA with qk-norm [hf:Qwen/Qwen3-4B]."""
 
 from repro.configs.base import LayerSlot, ModelConfig
 
@@ -18,7 +18,7 @@ def config() -> ModelConfig:
         rope_theta=1000000.0,
         decode_window=16384,
         slots=(LayerSlot("attn", "dense"),),
-        source="hf:Qwen/Qwen3-8B",
+        source="hf:Qwen/Qwen3-4B",
     )
 
 
@@ -37,5 +37,5 @@ def reduced_config() -> ModelConfig:
         rope_theta=1000000.0,
         decode_window=64,
         slots=(LayerSlot("attn", "dense"),),
-        source="hf:Qwen/Qwen3-8B",
+        source="hf:Qwen/Qwen3-4B",
     )

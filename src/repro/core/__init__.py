@@ -1,7 +1,6 @@
 """Core library: the paper's contribution (PowerSGD + EF-SGD) as composable
 JAX modules."""
 
-from repro import compat  # noqa: F401  (installs jax API shims)
 from repro.core.dist import (
     AXIS,
     AxisBackend,

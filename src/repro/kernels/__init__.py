@@ -12,8 +12,9 @@
   * ref.py      — pure-jnp oracles for the allclose tests; every oracle is
                   batched over leading dims exactly like the kernels
 
-All kernels accumulate in fp32 and are validated in interpret mode against
-``ref.py`` on CPU (the container cannot execute Mosaic); on TPU the same
-code path compiles to MXU matmuls with the rank dim padded to the 128 lane
+All kernels accumulate in fp32.  On the CPU they run in interpret mode
+and are checked against ``ref.py``; ``tests/test_chip_compile.py`` compiles
+each one with Mosaic for a described TPU v5e (no chip needed), where the
+low-rank matmuls go to the MXU with the rank dim padded to the 128 lane
 width.
 """

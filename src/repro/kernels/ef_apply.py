@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.lowrank import LANE
 
@@ -52,8 +53,9 @@ def _ef_apply_2d(x, mom, p_hat, q, lr, lam, block_n, block_m, interpret):
             pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
             pl.BlockSpec((bn, rp), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, rp), lambda i, j: (j, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            # lr and λ: Mosaic loads scalars from SMEM, never from ANY
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bn, bm), lambda i, j: (i, j)),

@@ -13,8 +13,9 @@ grid ``(B, n/bn, k/bk)`` with block size 1 on the batch axis — so one
 kernel per matrix (vmap would trace B copies; the batch grid dim is a
 single program).  Higher-rank inputs are flattened into the batch dim.
 
-Validated in interpret mode against :mod:`repro.kernels.ref` (the CPU
-container cannot execute Mosaic).
+Checked against :mod:`repro.kernels.ref` in interpret mode on the CPU
+(``tests/test_kernels.py``), and compiled by Mosaic for a TPU v5e at
+qwen3-4b widths (``tests/test_chip_compile.py``).
 """
 
 from __future__ import annotations

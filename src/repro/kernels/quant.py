@@ -7,8 +7,9 @@ combine/split on the VPU: the host strides the flat code vector into its
 even/odd halves (a layout change XLA fuses away), pads to the 128-lane
 width, and one elementwise grid kernel packs or unpacks a block at a time.
 
-Validated bit-exactly against :mod:`repro.kernels.ref` in interpret mode
-(``tests/test_wire_quant.py``); the CPU/test substrates use the reference
+Checked bit-exactly against :mod:`repro.kernels.ref` in interpret mode
+(``tests/test_wire_quant.py``) and compiled for a TPU v5e
+(``tests/test_chip_compile.py``); the CPU/test substrates use the reference
 path via the :mod:`repro.kernels.ops` dispatcher.
 """
 
@@ -23,10 +24,12 @@ BLOCK_ROWS = 256    # rows per grid step (multiple of the int8 32-sublane tile)
 
 
 def _pack_kernel(lo_ref, hi_ref, o_ref):
-    """o = (lo & 0xF) | ((hi & 0xF) << 4), elementwise over one block."""
-    lo = lo_ref[...].astype(jnp.uint8) & 0xF
-    hi = hi_ref[...].astype(jnp.uint8) & 0xF
-    o_ref[...] = lo | (hi << 4)
+    """o = (lo & 0xF) | ((hi & 0xF) << 4), elementwise over one block.
+
+    The bit arithmetic runs on int32: Mosaic has no 8-bit vector shift."""
+    lo = lo_ref[...].astype(jnp.int32) & 0xF
+    hi = hi_ref[...].astype(jnp.int32) & 0xF
+    o_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
 
 
 def _unpack_kernel(p_ref, lo_ref, hi_ref):

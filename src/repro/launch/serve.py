@@ -140,8 +140,8 @@ def main():
 
     cfg = get_config(args.arch, reduced=True)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((max(1, n_dev // 2), min(2, n_dev)),
-                         ("data", "model"))
+    mesh = mesh_lib.make_mesh((max(1, n_dev // 2), min(2, n_dev)),
+                              ("data", "model"))
     model_shards = mesh.shape["model"]
     print(f"serving {cfg.name} on mesh "
           f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")

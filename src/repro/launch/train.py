@@ -220,7 +220,16 @@ def make_train_step(cfg: ModelConfig, mesh, hyper: TrainHyper,
         return params_sds, ef_sds
 
     def init_state(key):
-        """Concrete initialisation (used by the real trainer on host devices)."""
+        """Concrete initialisation, built already laid out as the step takes
+        it: one jitted program whose ``out_shardings`` come from
+        :func:`abstract_state`, so each device materialises only its own
+        shard (the per-data-rank error buffers never sit on one device)."""
+        params_sds, ef_sds = abstract_state(key)
+        shardings = jax.tree_util.tree_map(lambda s: s.sharding,
+                                           (params_sds, ef_sds))
+        return jax.jit(_init_state, out_shardings=shardings)(key)
+
+    def _init_state(key):
         kp, kc = jax.random.split(key)
         params = model.init(kp, cfg, model_shards)
         dp_total = specs_lib.axis_sizes(mesh, dp_axes)
@@ -399,7 +408,9 @@ def main():
                                   save_train_state, stack_model_template)
     from repro.configs.base import get_config
     from repro.data.synthetic import MarkovLM
+    from repro.launch import compile_cache
 
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--steps", type=int, default=100)
@@ -429,7 +440,10 @@ def main():
                          "update while step t's gradients are computed "
                          "(error feedback absorbs the delay; see "
                          "docs/tuning.md)")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the config's reduced preset (default); "
+                         "--no-reduced trains at published widths")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0,
                     help="save a full TrainState checkpoint every N steps "
@@ -446,14 +460,14 @@ def main():
         ap.error("--ckpt-every requires --ckpt-dir (no checkpoint would "
                  "ever be written)")
 
-    cfg = get_config(args.arch, reduced=True)
+    cfg = get_config(args.arch, reduced=args.reduced)
     n_dev = len(jax.devices())
     if n_dev >= 4:
-        m = jax.make_mesh((n_dev // 2, 2), ("data", "model"))
+        m = mesh_lib.make_mesh((n_dev // 2, 2), ("data", "model"))
     elif n_dev >= 2:
-        m = jax.make_mesh((n_dev, 1), ("data", "model"))
+        m = mesh_lib.make_mesh((n_dev, 1), ("data", "model"))
     else:
-        m = jax.make_mesh((1, 1), ("data", "model"))
+        m = mesh_lib.make_mesh((1, 1), ("data", "model"))
 
     hyper = TrainHyper(lr=args.lr, rank=args.rank, q_chunk=64,
                        warmup_steps=20, remat=False,

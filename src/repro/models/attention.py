@@ -153,6 +153,12 @@ def forward(params, x, cfg: ModelConfig, ctx: MeshCtx, *, q_chunk: int = 512,
 
 
 def _full_chunks(q_chunks, k, v, qc, scale):
+    """Causal attention one q chunk at a time.
+
+    The chunk body is checkpointed: without it the scan's backward keeps
+    every chunk's (B, heads, qc, S) probabilities at once, the full S×S
+    score tensor the chunking exists to avoid (4 GiB a sequence in f32 at
+    32 heads and S=4096)."""
     s = k.shape[1]
 
     def one(carry, args):
@@ -167,7 +173,8 @@ def _full_chunks(q_chunks, k, v, qc, scale):
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
         return carry, out
 
-    _, outs = lax.scan(one, None, (jnp.arange(q_chunks.shape[0]), q_chunks))
+    _, outs = lax.scan(jax.checkpoint(one), None,
+                       (jnp.arange(q_chunks.shape[0]), q_chunks))
     return outs
 
 
@@ -195,7 +202,8 @@ def _windowed_chunks(q_chunks, k, v, qc, window, scale):
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, vs)
         return carry, out
 
-    _, outs = lax.scan(one, None, (jnp.arange(q_chunks.shape[0]), q_chunks))
+    _, outs = lax.scan(jax.checkpoint(one), None,
+                       (jnp.arange(q_chunks.shape[0]), q_chunks))
     return outs
 
 

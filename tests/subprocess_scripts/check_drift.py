@@ -60,6 +60,7 @@ from repro.configs.base import get_config
 from repro.core.simmesh import SimMesh
 from repro.data.synthetic import MarkovLM
 from repro.launch.train import TrainHyper, make_sim_train_step, make_train_step
+from repro.launch.mesh import make_mesh
 
 W, BATCH, SEQ = 4, 8, 128
 STEPS_LEGACY = 10      # documented drift is ~1e-2 by step 9 (docs/checkpoint.md)
@@ -135,7 +136,7 @@ def run_mesh(sync_mode, steps, mesh_shape=(4, 2), replicate_batch=False,
     cfg = get_config("llama3-8b", reduced=True)
     hyper = make_hyper(sync_mode, track_drift, tp_grad_sync)
     key = jax.random.key(0)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     mcoord = model_coord(mesh)
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     data = MarkovLM(vocab=cfg.vocab_size, seed=0)
@@ -229,7 +230,7 @@ def phase_equiv():
                 "labels": jnp.asarray(toks[:, 1:].copy())}
 
     # shard_map: data-parallel only, so per-rank local compute is comparable
-    mesh = jax.make_mesh((W, 1), ("data", "model"))
+    mesh = make_mesh((W, 1), ("data", "model"))
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     losses_mesh = []
     with jax.set_mesh(mesh):

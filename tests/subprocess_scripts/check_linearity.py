@@ -17,6 +17,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
 from repro.launch.train import TrainHyper, make_train_step
+from repro.launch.mesh import make_mesh
 from repro.configs.base import get_config
 from repro.data.synthetic import MarkovLM
 
@@ -25,7 +26,7 @@ def run(mesh_shape, steps=3):
     cfg = get_config("llama3-8b", reduced=True)
     hyper = TrainHyper(q_chunk=32, warmup_steps=5, remat=False)
     key = jax.random.key(0)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     data = MarkovLM(vocab=cfg.vocab_size, seed=0)
     it = data.batches(8, 64)

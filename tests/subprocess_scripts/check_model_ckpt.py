@@ -54,6 +54,7 @@ from repro.core.error_feedback import EFState
 from repro.data.synthetic import MarkovLM
 from repro.launch.train import (TrainHyper, make_train_step,
                                 train_state_partition)
+from repro.launch.mesh import make_mesh
 
 BATCH, SEQ = 8, 128
 SAVE_AT, STEPS = 3, 6
@@ -78,7 +79,7 @@ def setup():
     # "bit-exact resume" is a meaningful target on any substrate
     hyper = TrainHyper(lr=0.05, rank=2, q_chunk=64, warmup_steps=20,
                        remat=False, sync_mode="broadcast")
-    mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+    mesh = make_mesh(MESH_SHAPE, ("data", "model"))
     parts = train_state_partition(cfg, mesh)
     return cfg, hyper, mesh, parts
 

@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
 from repro.launch.serve import make_decode_step
 from repro.launch import specs as specs_lib
+from repro.launch.mesh import make_mesh
 from repro.configs.base import get_config, InputShape
 from repro.models import model as model_lib
 from repro.core.dist import SINGLE
@@ -28,7 +29,7 @@ def main():
                     InputShape("seqsharded", 64, 1, "decode")]:
             cfg = dataclasses.replace(get_config(arch, reduced=True),
                                       decode_window=0)
-            m = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            m = make_mesh((2, 2, 2), ("pod", "data", "model"))
             step_fn, _ = make_decode_step(cfg, m, shp)
             params = model_lib.init(key, cfg, 2)
             b = shp.global_batch

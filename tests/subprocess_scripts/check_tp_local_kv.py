@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 from repro.configs.base import LayerSlot, ModelConfig, InputShape
 from repro.core.dist import MeshCtx
 from repro.models import model as model_lib
+from repro.launch.mesh import make_mesh
 
 
 def cfg_with(local_kv: bool) -> ModelConfig:
@@ -32,7 +33,7 @@ def cfg_with(local_kv: bool) -> ModelConfig:
 
 def run(local_kv: bool):
     cfg = cfg_with(local_kv)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = MeshCtx(data_axes=("data",), model_axis="model",
                   seq_axes=("model",))
     key = jax.random.key(0)

@@ -12,6 +12,7 @@ import pytest
 from repro.configs.base import ARCH_IDS, get_config
 from repro.core.dist import SINGLE
 from repro.models import model as model_lib
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.key(0)
 
@@ -44,7 +45,7 @@ def test_one_train_step(arch):
     from repro.launch.train import TrainHyper, make_train_step
 
     cfg = get_config(arch, reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     hyper = TrainHyper(q_chunk=32, warmup_steps=2, remat=False, lr=0.05)
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     with jax.set_mesh(mesh):

@@ -10,6 +10,7 @@ from repro.configs.base import get_config
 from repro.core.dist import SINGLE
 from repro.data.synthetic import MarkovLM
 from repro.launch.train import TrainHyper, make_train_step
+from repro.launch.mesh import make_mesh
 from repro.models import model as model_lib
 
 KEY = jax.random.key(0)
@@ -17,7 +18,7 @@ KEY = jax.random.key(0)
 
 def _train(arch, steps, compressor=None, lr=0.1, seq=64, batch=8):
     cfg = get_config(arch, reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     hyper = TrainHyper(lr=lr, q_chunk=32, warmup_steps=5, remat=False,
                        weight_decay=0.0)
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper,
@@ -84,7 +85,7 @@ def test_checkpoint_resume_bitexact(tmp_path):
     from repro.checkpoint import restore_checkpoint, save_checkpoint
 
     cfg = get_config("yi-6b", reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     hyper = TrainHyper(lr=0.1, q_chunk=32, warmup_steps=5, remat=False)
     step_fn, _, init_state = make_train_step(cfg, mesh, hyper)
     data = MarkovLM(vocab=cfg.vocab_size, seed=0)

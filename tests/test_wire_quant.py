@@ -33,7 +33,7 @@ def _codes(n, seed, qmax=7):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=30)
+@settings(deadline=None, max_examples=30)
 @given(n=st.integers(min_value=1, max_value=700),
        seed=st.integers(min_value=0, max_value=2**31 - 1))
 def test_nibble_roundtrip_identity(n, seed):
@@ -46,7 +46,7 @@ def test_nibble_roundtrip_identity(n, seed):
     np.testing.assert_array_equal(np.asarray(back), codes)
 
 
-@settings(max_examples=20)
+@settings(deadline=None, max_examples=20)
 @given(n=st.integers(min_value=1, max_value=301),
        seed=st.integers(min_value=0, max_value=2**31 - 1))
 def test_nibble_odd_tail_padding(n, seed):
@@ -59,7 +59,7 @@ def test_nibble_odd_tail_padding(n, seed):
     assert np.asarray(ref.nibble_unpack(jnp.asarray(packed), n)).shape == (n,)
 
 
-@settings(max_examples=20)
+@settings(deadline=None, max_examples=20)
 @given(n=st.integers(min_value=1, max_value=1000),
        seed=st.integers(min_value=0, max_value=2**31 - 1))
 def test_pallas_matches_reference_bitexact(n, seed):
@@ -105,7 +105,7 @@ def test_ops_dispatch_cpu_routes_to_reference():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=25)
+@settings(deadline=None, max_examples=25)
 @given(n=st.integers(min_value=1, max_value=400),
        seed=st.integers(min_value=0, max_value=2**31 - 1),
        log_mag=st.integers(min_value=-8, max_value=8))

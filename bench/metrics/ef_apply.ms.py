@@ -1,0 +1,12 @@
+"""Device time of the error-feedback update per step, ms: the step's ops
+under the ``ef_apply`` named scope and under no scope nested in it (weight
+decay, delta = gradient + error, the new error, momentum and the parameter
+write), averaged over the chips."""
+
+
+def read(run):
+    tr = run.get("trace")
+    if not tr or not tr.get("scope_s"):
+        return None
+    per = [d["ef_apply"] for d in tr["scope_s"]]
+    return 1e3 * sum(per) / len(per) / run["steps"]

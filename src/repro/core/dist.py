@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import scopes
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _psum_identity_bwd(x, axes):
@@ -462,6 +464,7 @@ class MeshCtx:
         return (numer.astype(total.dtype) / denom).astype(x.dtype)
 
     # -- data-parallel collectives (gradient aggregation) ------------------
+    @scopes.scoped(scopes.EXCHANGE)
     def psum_data(self, x, *, sync: Optional[bool] = None):
         self._record_data(x)
         if not self.data_axes:
@@ -472,6 +475,7 @@ class MeshCtx:
             return self._canonical_reduce(x, mean=False)
         return self.backend.psum(x, self.data_axes)
 
+    @scopes.scoped(scopes.EXCHANGE)
     def pmean_data(self, x, *, sync: Optional[bool] = None):
         self._record_data(x)
         if not self.data_axes:
@@ -482,6 +486,7 @@ class MeshCtx:
             return self._canonical_reduce(x, mean=True)
         return self.backend.pmean(x, self.data_axes)
 
+    @scopes.scoped(scopes.EXCHANGE)
     def pmean_flat(self, parts: Sequence[jax.Array], *,
                    wire_dtype: str = "auto",
                    max_chunk_bytes: Optional[int] = None,
@@ -571,6 +576,7 @@ class MeshCtx:
             out.update(matrixize.unpack_flat(*pending))
         return [out[i] for i in range(len(parts))]
 
+    @scopes.scoped(scopes.EXCHANGE)
     def broadcast_flat(self, parts: Sequence[jax.Array], *,
                        wire_dtype: str = "auto",
                        max_chunk_bytes: Optional[int] = None) -> List[jax.Array]:
@@ -606,6 +612,7 @@ class MeshCtx:
             out.update(matrixize.unpack_flat(chunk, buf))
         return [out[i] for i in range(len(parts))]
 
+    @scopes.scoped(scopes.EXCHANGE)
     def allgather_flat(self, parts: Sequence[jax.Array], *,
                        wire_dtype: str = "auto",
                        max_chunk_bytes: Optional[int] = None) -> List[jax.Array]:

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import matrixize
+from repro.core import matrixize, scopes
 from repro.core.compressors import Compressor
 from repro.core.dist import MeshCtx, SINGLE
 
@@ -173,6 +173,7 @@ def replace_comp(state: EFState, comp) -> EFState:
                    step=state.step, inflight=state.inflight)
 
 
+@scopes.scoped(scopes.EF_APPLY)
 def apply_updates(
     compressor: Compressor,
     params,
@@ -223,11 +224,14 @@ def apply_updates(
     # Δ_w = g_w + e_w
     deltas = jax.tree_util.tree_map(jnp.add, grads, state.error)
 
-    if start_compress_step:
-        out = _warmup_or_compress(compressor, deltas, state.comp, specs,
-                                  ctx, key, state.step, start_compress_step)
-    else:
-        out = compressor.step(deltas, state.comp, specs, ctx=ctx, key=key)
+    with jax.named_scope(scopes.COMPRESS):
+        if start_compress_step:
+            out = _warmup_or_compress(compressor, deltas, state.comp, specs,
+                                      ctx, key, state.step,
+                                      start_compress_step)
+        else:
+            out = compressor.step(deltas, state.comp, specs, ctx=ctx,
+                                  key=key)
 
     # e_w = Δ_w − recon
     new_error = jax.tree_util.tree_map(jnp.subtract, deltas, out.recon)

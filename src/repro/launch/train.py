@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import error_feedback, matrixize
+from repro.core import error_feedback, matrixize, scopes
 from repro.core.compressors import Compressor, PowerSGDCompressor
 from repro.core.dist import MeshCtx
 from repro.core.error_feedback import EFState
@@ -156,7 +156,8 @@ def make_train_step(cfg: ModelConfig, mesh, hyper: TrainHyper,
                                  q_chunk=hyper.q_chunk, remat=hyper.remat,
                                  unroll=hyper.unroll)
 
-        grads, metrics = jax.grad(loss_fn, has_aux=True)(params)
+        with jax.named_scope(scopes.LOSS_GRAD):
+            grads, metrics = jax.grad(loss_fn, has_aux=True)(params)
 
         lr = _schedule(hyper, state.step)
         new_params, new_state, aux = error_feedback.apply_updates(
@@ -324,7 +325,8 @@ def make_sim_train_step(cfg: ModelConfig, sim, hyper: TrainHyper,
                                  q_chunk=hyper.q_chunk, remat=hyper.remat,
                                  unroll=hyper.unroll)
 
-        grads, metrics = jax.grad(loss_fn, has_aux=True)(params)
+        with jax.named_scope(scopes.LOSS_GRAD):
+            grads, metrics = jax.grad(loss_fn, has_aux=True)(params)
 
         lr = _schedule(hyper, ef_state.step)
         new_params, new_state, aux = error_feedback.apply_updates(

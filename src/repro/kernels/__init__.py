@@ -6,6 +6,10 @@
                   leading batch grid dimension so one ``pallas_call``
                   covers the whole bucket.
   * ef_apply.py — fused decompress + momentum + parameter update
+  * flash_attention.py — causal flash attention (forward, dk/dv, dq) for
+                  the model's attention core on the TPU; selected by
+                  platform and shape in ``models/attention.py``, not by
+                  ``use_pallas``
   * ops.py      — jit'd public wrappers (`lowrank_project`,
                   `lowrank_backproject`, `ef_apply`); rank-polymorphic over
                   leading batch dims

@@ -38,7 +38,9 @@ class TrainHyper:
     weight_decay: float = 1e-4
     warmup_steps: int = 200
     rank: int = 2
-    q_chunk: int = 512
+    q_chunk: int = 512              # query rows per block of the XLA
+    #   attention path; the TPU's flash kernels (full causal attention at
+    #   aligned shapes, see models/attention.py) size their own blocks
     window: int = 0                 # sliding-window attention (0 = full)
     remat: bool = True
     unroll: int = 1                 # scan unroll (dry-run cost accounting)

@@ -1,6 +1,17 @@
-"""GQA attention with Megatron-style tensor parallelism, chunked (flash-like)
-causal attention for train/prefill, and a sequence-sharded KV cache with
-logsumexp merging for decode.
+"""GQA attention with Megatron-style tensor parallelism, causal attention
+for train/prefill, and a sequence-sharded KV cache with logsumexp merging
+for decode.
+
+Which train/prefill core runs where (:func:`_attend`):
+  * lowered for the TPU, full causal attention (no window) at a sequence
+    that a kernel block divides and a head_dim of whole 128-lane tiles
+    runs the Pallas flash kernels of ``repro.kernels.flash_attention``
+    (``flash_attention.applies``), on the unexpanded K/V where the local
+    q head → kv head map is ``arange // group``;
+  * everything else (other platforms, sliding windows, unaligned shapes
+    such as MusicGen's head_dim 64) runs the chunked XLA path, one
+    ``q_chunk``-row block of queries at a time; ``q_chunk`` has no effect
+    on the kernel path.
 
 Sharding:
   * Q heads are padded to a multiple of ``model_shards`` and column-split;
@@ -26,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.dist import MeshCtx
 from repro.core.matrixize import MatrixSpec, NONE as SPEC_NONE
+from repro.kernels import flash_attention
 from repro.models import common
 from repro.configs.base import ModelConfig
 
@@ -131,9 +143,45 @@ def forward(params, x, cfg: ModelConfig, ctx: MeshCtx, *, q_chunk: int = 512,
         kv_idx = jnp.arange(hl) // group       # local kv index
     else:
         kv_idx = jnp.minimum(gheads, cfg.num_heads - 1) // group
-    k_h = jnp.take(k, kv_idx, axis=2)          # (B, S, hl, hd)
-    v_h = jnp.take(v, kv_idx, axis=2)
+    # the map is arange(hl) // group when the kv heads are local or unsharded
+    grouped = local_kv or shards == 1
 
+    out = _attend(q, k, v, kv_idx, grouped, q_chunk=q_chunk, window=window,
+                  scale=scale)
+    # mask padded heads so they contribute nothing (and get no gradient)
+    out = jnp.where((gheads < cfg.num_heads)[None, None, :, None], out, 0.0)
+    out = out.reshape(b, s, hl * hd)
+    return ctx.psum_model(out @ params["wo"])
+
+
+def _attend(q, k, v, kv_idx, grouped, *, q_chunk, window, scale):
+    """The attention core: (B, S, hl, hd) outputs of q (B, S, hl, hd) over
+    k, v (B, S, kv, hd), q head h reading kv head ``kv_idx[h]``.
+
+    Lowered for the TPU, full causal attention at aligned shapes
+    (``flash_attention.applies``) runs the flash kernels, on the
+    unexpanded K/V where ``grouped`` says ``kv_idx`` is ``arange // group``.
+    Everything else (other platforms, sliding windows, unaligned shapes)
+    runs :func:`_chunked`."""
+    expand = lambda x: jnp.take(x, kv_idx, axis=2)       # (B, S, hl, hd)
+
+    def chunked(q, k, v):
+        return _chunked(q, expand(k), expand(v), q_chunk, window, scale)
+
+    if not flash_attention.applies(q.shape[1], q.shape[3], window):
+        return chunked(q, k, v)
+
+    def flash(q, k, v):
+        if not grouped:
+            k, v = expand(k), expand(v)
+        return flash_attention.causal_attention(q, k, v)
+
+    return lax.platform_dependent(q, k, v, tpu=flash, default=chunked)
+
+
+def _chunked(q, k_h, v_h, q_chunk, window, scale):
+    """Attention one q chunk at a time in XLA, K/V expanded to q's heads."""
+    b, s, hl, hd = q.shape
     qc = min(q_chunk, s)
     n_chunks = (s + qc - 1) // qc
     s_pad = n_chunks * qc
@@ -145,11 +193,7 @@ def forward(params, x, cfg: ModelConfig, ctx: MeshCtx, *, q_chunk: int = 512,
     else:
         out_chunks = _full_chunks(q_chunks, k_h, v_h, qc, scale)
 
-    out = out_chunks.transpose(1, 0, 2, 3, 4).reshape(b, s_pad, hl, hd)[:, :s]
-    # mask padded heads so they contribute nothing (and get no gradient)
-    out = jnp.where((gheads < cfg.num_heads)[None, None, :, None], out, 0.0)
-    out = out.reshape(b, s, hl * hd)
-    return ctx.psum_model(out @ params["wo"])
+    return out_chunks.transpose(1, 0, 2, 3, 4).reshape(b, s_pad, hl, hd)[:, :s]
 
 
 def _full_chunks(q_chunks, k, v, qc, scale):

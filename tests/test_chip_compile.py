@@ -16,14 +16,18 @@ not depend on which worker got it.  Every kernel is called with
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_config
+from repro.core.dist import SINGLE
 from repro.kernels import ef_apply, lowrank, quant
 from repro.launch import compile_cache
+from repro.models import attention
 
 # qwen3-4b's two MLP matrix shapes (d_model 2560, d_ff 9728), both ways up
 SHAPES = [(2560, 9728), (9728, 2560)]
@@ -107,3 +111,22 @@ def test_nibble_unpack_compiles(one_chip, n):
     _assert_kernel_compiles(
         lambda p: quant.nibble_unpack(p, n, interpret=False),
         _sds(one_chip, ((n + 1) // 2,), jnp.uint8))
+
+
+# the attention of both benchmark cells: qwen3-4b (32 q over 8 kv heads,
+# qk-norm) at batch 1 and olmoe-1b-7b (16 heads) at batch 2, seq 4096
+@pytest.mark.parametrize("name,batch", [("qwen3-4b", 1), ("olmoe-1b-7b", 2)])
+def test_attention_grad_takes_flash_kernels(one_chip, name, batch):
+    cfg = get_config(name)
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape),
+        jax.eval_shape(lambda: attention.init(jax.random.key(0), cfg, 1)))
+    x = _sds(one_chip, (batch, 4096, cfg.d_model))
+    loss = lambda p, x: jnp.sum(attention.forward(p, x, cfg, SINGLE) ** 2)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_dkv",
+                   "flash_attention_dq"):
+        assert kernel in text
+    # no chunk of scores, (..., 512 queries, 4096 keys), reaches HBM
+    assert not re.search(r"f32\[[\d,]*512,4096\]", text)

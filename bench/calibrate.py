@@ -59,7 +59,7 @@ def main(argv=None) -> int:
     devices = devices[:cell.chips]
     out = open(args.out, "a") if args.out else None
     steps = cell.traffic["check_steps"]
-    arch = reference.Arch.from_config(cell.config)
+    model = reference.Model.of(cell.arch, cell.config)
     optim = reference.Optim.from_traffic(cell.traffic)
     faults = [f for f in reference.FAULTS
               if f != "no_exchange" or cell.data_ranks > 1]
@@ -90,20 +90,20 @@ def main(argv=None) -> int:
         t_prog = time.perf_counter() - t
         batches = program.batches_for_check(cell, ring_np, steps)
         t = time.perf_counter()
-        ref = reference.train(arch, optim, key, batches, devices)
+        ref = reference.train(model, optim, key, batches, devices)
         t_ref = time.perf_counter() - t
         emit("program", seed, check.numbers(got, ref),
              {"program": t_prog, "reference": t_ref})
         if n < args.control:
             t = time.perf_counter()
-            ctl = reference.train(arch, optim, key, batches, devices,
+            ctl = reference.train(model, optim, key, batches, devices,
                                   dtype=jnp.bfloat16, precision="default")
             emit("control", seed, check.numbers(ctl, ref),
                  time.perf_counter() - t)
         if n < args.faults:
             for fault in faults:
                 t = time.perf_counter()
-                bad = reference.train(arch, optim, key, batches, devices,
+                bad = reference.train(model, optim, key, batches, devices,
                                       fault=fault)
                 emit(f"fault:{fault}", seed, check.numbers(bad, ref),
                      time.perf_counter() - t)

@@ -4,7 +4,11 @@ Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric lives in a file of its own, found by name:
 
 * ``bench/configs/<config>.json``: the model as run (its published key
-  names), the registry name and the keys changed from it;
+  names), the registry name, the keys changed from it and, optionally, the
+  name of its architecture (``"arch"``, default ``decoder``);
+* ``bench/archs/<arch>.py``: the plain model of that architecture for the
+  reference (``reference.Model``), its FLOPs per token and the program
+  fields its published keys name;
 * ``bench/traffic/<traffic>.json``: sequence, batch per data rank, mesh,
   compressor and every ``TrainHyper`` field, pinned;
 * ``bench/limits/<cell>.json``: the limit of each number the check compares;
@@ -18,9 +22,15 @@ import importlib.util
 import json
 import math
 import pathlib
+import sys
 
 BENCH = pathlib.Path(__file__).resolve().parent
 CHECKOUT = BENCH.parent
+# where a file found by name (``<dir>/archs/<arch>.py``,
+# ``<dir>/metrics/<metric>.py``) is looked for, first hit wins; tests put a
+# directory of their own in front
+SEARCH = (BENCH,)
+DEFAULT_ARCH = "decoder"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +54,15 @@ class Cell:
     @property
     def tokens_per_step(self) -> int:
         return self.global_rows * self.traffic["seq"]
+
+    @property
+    def arch(self):
+        """The configuration's architecture module."""
+        return arch_module(self.config)
+
+    @property
+    def flops_per_token(self) -> float:
+        return self.arch.flops_per_token(self.config, self.traffic["seq"])
 
 
 def _json(path: pathlib.Path) -> dict:
@@ -70,32 +89,42 @@ def load(name: str, benchmark: pathlib.Path = CHECKOUT / "BENCHMARK.json"
         per_layer=tuple(m for m in spec["per_layer"] if mine(m)))
 
 
+def _module(kind: str, name: str):
+    """The module of ``<dir>/<kind>/<name>.py`` for the first ``dir`` of
+    :data:`SEARCH` that has it, loaded once per file."""
+    path = next((p for p in (d / kind / f"{name}.py" for d in SEARCH)
+                 if p.is_file()), None)
+    if path is None:
+        raise SystemExit(f"no {kind}/{name}.py under "
+                         f"{[str(d) for d in SEARCH]}")
+    key = f"bench_{kind}_" + name.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(key)
+    if mod is None or mod.__file__ != str(path):
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str):
     """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric).read
 
 
-# the program's ModelConfig fields each published key of a config file
-# names; the harness checks that the program builds the model the file states
+def arch_module(config: dict):
+    """The architecture module ``bench/archs/<arch>.py`` of a config file,
+    ``arch`` its ``"arch"`` key (default ``decoder``)."""
+    return _module("archs", config.get("arch", DEFAULT_ARCH))
+
+
+# the program's ModelConfig fields that published keys every config file has
+# name; an architecture module's ``PROGRAM_FIELDS`` adds its own.  The harness
+# checks that the program builds the model the file states
 PROGRAM_FIELDS = {
     "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
     "num_hidden_layers": "num_layers",
     "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "qk_norm": "qk_norm",
-    "num_experts": "moe_num_experts",
-    "num_experts_per_tok": "moe_top_k",
-    "capacity_factor": "moe_capacity_factor",
-    "router_aux_loss_coef": "moe_aux_weight",
 }
 
 
@@ -106,7 +135,9 @@ def program_config(config: dict):
 
     cfg = dataclasses.replace(get_config(config["registry"]),
                               **config["changed"])
-    for key, field in PROGRAM_FIELDS.items():
+    fields = {**PROGRAM_FIELDS,
+              **getattr(arch_module(config), "PROGRAM_FIELDS", {})}
+    for key, field in fields.items():
         if key not in config:
             continue
         have, want = getattr(cfg, field), config[key]

@@ -1,6 +1,7 @@
 """Device time of the model's forward and backward per step, ms: the step's
-ops under the ``loss_grad`` named scope (forward, remat recompute, backward
-and the model-axis collectives), averaged over the chips."""
+ops under the ``loss_grad`` named scope, any scope nested in it included
+(forward, remat recompute, backward and the model-axis collectives),
+averaged over the chips."""
 
 
 def read(run):

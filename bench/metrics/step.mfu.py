@@ -1,8 +1,8 @@
 """Whole-step model FLOP/s utilization, %: the traced window's tokens per
-second × model FLOPs per token (``bench/flops.py``) over chips × the bf16
-peak (``bench/peaks.py``).  The step runs float32 at default matmul
-precision, which on this chip is one bf16 pass, so the bf16 peak is the
-base."""
+second × model FLOPs per token (``flops_per_token`` of the configuration's
+``bench/archs/<arch>.py``) over chips × the bf16 peak (``bench/peaks.py``).
+The step runs float32 at default matmul precision, which on this chip is
+one bf16 pass, so the bf16 peak is the base."""
 
 
 def read(run):

@@ -5,17 +5,15 @@ jitted ``shard_map`` step through ``models/``, ``core/error_feedback.py``,
 ``core/powersgd.py`` via ``core/engine.py``, and ``core/dist.py``.  One
 object holds the compiled step, its state and the cell's ring of batches on
 the device; set-up drives it through the first steps the check compares,
-and the window then continues the same object.
+and the window (``scopetrace.window``) then continues the same object.
 """
 
 from __future__ import annotations
 
 import math
-import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import cell as cell_lib
@@ -121,32 +119,6 @@ def _first_grad_norms(ef):
 def _change_norms(params, params0):
     return reference.leaf_norms(jax.jit(lambda x, y: jax.tree_util.tree_map(
         jnp.subtract, x, y))(params, params0))
-
-
-def window(prog: Program, state, ring, key, first: int, seconds: float,
-           annotate: bool = False):
-    """Whole steps from the first dispatch to ``block_until_ready`` on the
-    last, until ``seconds`` have passed; at most two steps in flight."""
-    losses = []
-    i = first
-    t0 = time.perf_counter()
-    while True:
-        if annotate:
-            with jax.profiler.StepTraceAnnotation("train", step_num=i):
-                state, metrics = prog.step(state, ring[i % len(ring)], key, i)
-        else:
-            state, metrics = prog.step(state, ring[i % len(ring)], key, i)
-        losses.append(metrics["lm_loss"])
-        i += 1
-        if len(losses) >= 2:
-            losses[-2].block_until_ready()
-        if time.perf_counter() - t0 >= seconds:
-            break
-    jax.block_until_ready(state)
-    elapsed = time.perf_counter() - t0
-    losses = np.asarray([float(x) for x in losses])
-    return state, {"steps": len(losses), "seconds": elapsed,
-                   "failed": int(np.sum(~np.isfinite(losses)))}
 
 
 def make_ring(cell: cell_lib.Cell, seed: int):

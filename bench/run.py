@@ -9,10 +9,12 @@ compiled for the cell's one shape (JAX's persistent cache in
 device, and the first steps the check compares.  The window then times
 whole steps of the same object for ``--seconds``.  With ``--trace 1`` the
 window is profiled and the cell's per-layer metrics are printed instead of
-its end-to-end ones.
+its end-to-end ones; set-up then also maps the compiled step's instructions
+to the program's named scopes (``bench/scopetrace.py``).
 
 After the window the program's state is freed and the plain reference
-(``bench/reference.py``) follows the same first steps; ``correct`` says
+(``bench/reference.py`` with the configuration's ``bench/archs/<arch>.py``)
+follows the same first steps; ``correct`` says
 whether the program's readings are within the limits of
 ``bench/limits/<cell>.json``.  The last line of standard output is one JSON
 object; the numbers compared are also the last lines of standard error.
@@ -28,7 +30,6 @@ import argparse  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
 
 BENCH = pathlib.Path(__file__).resolve().parent
@@ -36,8 +37,6 @@ CHECKOUT = BENCH.parent
 for p in (str(CHECKOUT / "src"), str(BENCH)):
     if p not in sys.path:
         sys.path.insert(0, p)
-
-TRACE_DIR = CHECKOUT / ".bench_trace"
 
 
 def log(*a):
@@ -55,40 +54,16 @@ def peak_in_use(devices) -> int:
                for d in devices)
 
 
-def traced_window(prog, state, ring, key, first, seconds):
-    import jax
-
-    import program
-    import tracereduce
-
-    shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.enable_hlo_proto = False
-    jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
-    try:
-        with jax.profiler.TraceAnnotation(tracereduce.WINDOW):
-            state, win = program.window(prog, state, ring, key, first,
-                                        seconds, annotate=True)
-    finally:
-        jax.profiler.stop_trace()
-    try:
-        summary = tracereduce.reduce(tracereduce.load(str(TRACE_DIR)))
-    finally:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    return state, win, summary
-
-
 def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
              t0: float = None) -> dict:
     """One run of ``cell``; returns the result line as a dict."""
     import jax
 
     import check
-    import flops
     import peaks
     import program
     import reference
+    import scopetrace
     from cell import reader
 
     t0 = time.perf_counter() if t0 is None else t0
@@ -99,18 +74,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     ring = prog.put_ring(ring_np)
     key = program.base_key(seed)
     prog.compile(ring[0], jax.random.fold_in(key, 0))
+    if trace:
+        step = scopetrace.step_scopes(prog, ring[0],
+                                      jax.random.fold_in(key, 0))
     state = prog.init(key)
     state, prog_read = prog.first_steps(state, ring, key, steps)
     setup_s = time.perf_counter() - t0
     log(f"{cell.name}: set-up {setup_s:.2f} s; first losses "
         f"{prog_read.losses}")
 
-    summary = None
     if trace:
-        state, win, summary = traced_window(prog, state, ring, key, steps,
-                                            seconds)
+        (state, win), tl = scopetrace.traced(lambda: scopetrace.window(
+            prog, state, ring, key, steps, seconds))
+        summary = scopetrace.reduce(tl, step)
     else:
-        state, win = program.window(prog, state, ring, key, steps, seconds)
+        state, win = scopetrace.window(prog, state, ring, key, steps,
+                                       seconds)
     tokens_per_s = win["steps"] * cell.tokens_per_step / win["seconds"]
     log(f"{cell.name}: {win['steps']} steps in {win['seconds']:.3f} s, "
         f"{tokens_per_s:.1f} tokens/s")
@@ -120,7 +99,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     gc.collect()
 
     ref = reference.train(
-        reference.Arch.from_config(cell.config),
+        reference.Model.of(cell.arch, cell.config),
         reference.Optim.from_traffic(cell.traffic), key,
         program.batches_for_check(cell, ring_np, steps), devices)
     nums = check.numbers(prog_read, ref)
@@ -133,8 +112,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     if trace:
         run = {"tokens_per_s": tokens_per_s, "steps": win["steps"],
                "chips": len(devices), "trace": summary,
-               "flops_per_token": flops.flops_per_token(
-                   cell.config, cell.traffic["seq"]),
+               "flops_per_token": cell.flops_per_token,
                "peak_flops": peaks.peaks(info["kind"])["bf16_flops"],
                "compiled_peak_bytes": compiled_peak}
         metrics = {}

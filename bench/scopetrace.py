@@ -7,13 +7,19 @@ its waits (:func:`window`) and each garbage collection of its Python
 
 * :func:`load` is ``tracereduce.load`` that also keeps each device op's HLO
   module and each ``train`` span's step number;
-* :func:`scope_times` gives, per device, the step module's time in each
-  scope of ``repro.core.scopes`` (``op_scopes`` of the compiled step's HLO
-  text) and in none;
+* :class:`StepScopes` reads the compiled step's HLO text: each
+  instruction's innermost scope of ``repro.core.scopes`` (``op_scopes``),
+  the scopes each scope lies within (from the ``op_name`` paths) and the
+  step's module; :func:`step_scopes` takes it from a compiled step, compiled
+  again where a cached executable maps no scope;
+* :func:`scope_times` gives, per device, the step module's time in each of
+  the program's scopes, nested scopes included, and in none;
 * :func:`idle_gaps` labels each of device 0's longest idle gaps
   ``host: <innermost span> (step <n>, +<t> s)``: the step whose ``train``
   span last began, and the gap's offset from the window's start;
-* :func:`reduce` is ``tracereduce.reduce`` with these.
+* :func:`reduce` is ``tracereduce.reduce`` with these;
+* :func:`window` is the timed loop, each host wait under a span, and
+  :func:`traced` runs it under the profiler and loads the trace.
 
 Run as a script, it measures one cell on the chip::
 
@@ -25,9 +31,9 @@ a traced one continuing the same state.  The last line of standard output
 is one JSON object: both windows' tokens per second, compiles, garbage
 collections and slowest dispatch interval; the traced window's per-layer
 metrics (each ``bench/metrics/<name>.py`` whose reading is not ``None``),
-milliseconds per step in each scope, the ops that took most time with
-their scopes, the labelled gaps, and how far each step's ``train`` span
-began before the step's first device op.  With ``--out``, the timeline it
+milliseconds per step in each scope (nested scopes included), the ops that
+took most time with their scopes, the labelled gaps, and how far each
+step's ``train`` span began before the step's first device op.  With ``--out``, the timeline it
 reduced is written there for a second look.
 """
 
@@ -68,10 +74,6 @@ from repro.core import scopes  # noqa: E402
 STEP, WAIT, DRAIN, LOSSES = "train", "step.wait", "window.drain", \
     "window.losses"
 GC = "python.gc"      # a garbage collection of the host's Python
-# innermost first: an instant covered by ops of two scopes (a loop and the
-# ops of its body) goes to the inner one
-NESTING = (scopes.EXCHANGE, scopes.COMPRESS, scopes.EF_APPLY,
-           scopes.LOSS_GRAD)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_DIR = CHECKOUT / ".bench_trace"
 
@@ -143,25 +145,78 @@ def _module_ops(tl: ScopedTimeline, d: int, module: str):
             if m == module and e > lo and s < hi]
 
 
-def scope_times(tl: ScopedTimeline, op_scope: Dict[str, str],
-                module: str) -> dict:
-    """Per device: seconds of the window in which an op of ``module`` that
-    ``op_scope`` maps to each scope runs, as the union of those ops'
-    intervals (a loop and its body count once); an instant two scopes cover
-    goes to the inner one.  ``unscoped_s`` is the module's busy time under
-    no scope, so the scopes and it sum to ``module_busy_s``."""
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@dataclasses.dataclass(frozen=True)
+class StepScopes:
+    """Where the compiled step's work lies: each instruction's innermost
+    scope (``scopes.op_scopes``), the scopes each scope lies within
+    anywhere in the step, and the step's HLO module.  Empty where the
+    step's HLO maps no scope."""
+
+    op_scope: Dict[str, str]
+    within: Dict[str, frozenset]
+    module: Optional[str]
+
+    @classmethod
+    def of(cls, hlo_text: str) -> "StepScopes":
+        """From ``compiled.as_text()``: a scope lies within each scope that
+        comes before it in some instruction's ``op_name`` path."""
+        within = {}
+        for path in set(_OP_NAME.findall(hlo_text)):
+            chain = [c for c in map(scopes.scope_of, path.split("/")) if c]
+            for i, c in enumerate(chain):
+                outer = set(chain[:i]) - {c}
+                if outer:
+                    within.setdefault(c, set()).update(outer)
+        return cls(scopes.op_scopes(hlo_text),
+                   {k: frozenset(v) for k, v in within.items()},
+                   scopes.module_name(hlo_text))
+
+
+def step_scopes(prog, batch, key) -> StepScopes:
+    """:class:`StepScopes` of ``prog``'s compiled step.  JAX leaves op
+    metadata out of its persistent-cache key, so an executable loaded from
+    the cache can carry the metadata of another build that maps no scope;
+    then the step is compiled once more with the cache off, and standard
+    error says so."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    step = StepScopes.of(prog.compiled.as_text())
+    if step.op_scope:
+        return step
+    print("scopetrace: the cached step maps no scope; compiling it again "
+          "with the persistent cache off", file=sys.stderr, flush=True)
+    jax.clear_caches()
+    with compile_cache.disabled():
+        prog.compile(batch, key)
+    return StepScopes.of(prog.compiled.as_text())
+
+
+def scope_times(tl: ScopedTimeline, step: StepScopes) -> dict:
+    """Per device: seconds of the window in which an op of the step module
+    runs under each of the program's scopes (``scopes.SCOPES``), as the
+    union of the intervals of the ops whose innermost scope is that scope
+    or one that lies within it (a loop and its body count once; so does a
+    nested scope's time, in the scope and in every scope around it).
+    ``unscoped_s`` is the module's busy time under no scope, and
+    ``module_busy_s`` its busy time."""
     scope_s, unscoped, busy = [], [], []
     for d in range(len(tl.devices)):
-        ops = _module_ops(tl, d, module)
-        every = merge((s, e) for _, s, e in ops)
-        taken, per = [], {}
-        for scope in NESTING:
-            iv = merge((s, e) for n, s, e in ops if op_scope.get(n) == scope)
-            per[scope] = length(subtract(iv, taken)) * 1e-9
-            taken = merge(taken + iv)
-        scope_s.append(per)
+        ops = [(s, e, step.op_scope.get(n))
+               for n, s, e in _module_ops(tl, d, step.module)]
+        every = merge((s, e) for s, e, _ in ops)
+        scope_s.append({
+            scope: length(merge(
+                (s, e) for s, e, c in ops
+                if c == scope or scope in step.within.get(c, ()))) * 1e-9
+            for scope in scopes.SCOPES})
+        scoped = merge((s, e) for s, e, c in ops if c is not None)
         busy.append(length(every) * 1e-9)
-        unscoped.append(length(subtract(every, taken)) * 1e-9)
+        unscoped.append(length(subtract(every, scoped)) * 1e-9)
     return {"scope_s": scope_s, "unscoped_s": unscoped,
             "module_busy_s": busy}
 
@@ -186,15 +241,15 @@ def idle_gaps(tl: ScopedTimeline, top: int = 10) -> list:
             if e - s >= tracereduce.MIN_GAP_NS]
 
 
-def reduce(tl: ScopedTimeline, op_scope: Optional[Dict[str, str]] = None,
-           module: Optional[str] = None, top: int = 10) -> dict:
+def reduce(tl: ScopedTimeline, step: Optional[StepScopes] = None,
+           top: int = 10) -> dict:
     """``tracereduce.reduce`` with labelled gaps and, given the step's
-    ``op_scope`` map and module, :func:`scope_times` (none for a step
-    compiled without the scopes, whose map is empty)."""
+    scopes, :func:`scope_times` (none for a step whose HLO maps no
+    scope)."""
     r = tracereduce.reduce(tl, top)
     r["idle_gaps"] = idle_gaps(tl, top)
-    if op_scope:
-        r.update(scope_times(tl, op_scope, module))
+    if step is not None and step.op_scope:
+        r.update(scope_times(tl, step))
     return r
 
 
@@ -267,7 +322,9 @@ class Collections:
 
 
 def window(prog, state, ring, key, first: int, seconds: float):
-    """``program.window`` with each host wait under a span of its own:
+    """The timed window: whole steps of ``prog`` from the first dispatch to
+    ``block_until_ready`` on the last, until ``seconds`` have passed, at
+    most two steps in flight.  Each host wait is under a span of its own:
     ``train`` around each dispatch, ``step.wait`` around the wait on the
     previous step's loss, ``window.drain`` around the final wait and
     ``window.losses`` around reading the losses (the spans cost nothing
@@ -305,6 +362,28 @@ def window(prog, state, ring, key, first: int, seconds: float):
                    "slowest": slowest}
 
 
+def traced(run):
+    """``run()`` under the profiler, inside the window's host span; returns
+    its result and the trace's :class:`ScopedTimeline`.  The trace is
+    deleted once read."""
+    import jax
+
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(tracereduce.WINDOW):
+            result = run()
+    finally:
+        jax.profiler.stop_trace()
+    try:
+        return result, load(str(TRACE_DIR))
+    finally:
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+
+
 def _dump(tl: ScopedTimeline, op_scope, out: pathlib.Path,
           name: str) -> str:
     """The timeline and the scope map, gzipped JSON, for a second look."""
@@ -319,7 +398,6 @@ def _dump(tl: ScopedTimeline, op_scope, out: pathlib.Path,
 def measure(cell, seed: int, seconds: float, devices, out=None) -> dict:
     import jax
 
-    import flops
     import peaks
     import program
     from cell import reader
@@ -330,6 +408,7 @@ def measure(cell, seed: int, seconds: float, devices, out=None) -> dict:
     ring = prog.put_ring(program.make_ring(cell, seed))
     key = program.base_key(seed)
     prog.compile(ring[0], jax.random.fold_in(key, 0))
+    step = step_scopes(prog, ring[0], jax.random.fold_in(key, 0))
     state = prog.init(key)
     state, _ = prog.first_steps(state, ring, key, steps)
     setup_s = time.perf_counter() - T0
@@ -337,33 +416,22 @@ def measure(cell, seed: int, seconds: float, devices, out=None) -> dict:
     with Compiles() as plain_c, Collections() as plain_gc:
         state, plain = window(prog, state, ring, key, steps, seconds)
     first = steps + plain["steps"]
-    shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.enable_hlo_proto = False
-    jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
-    try:
-        with Compiles() as traced_c, Collections() as traced_gc, \
-                jax.profiler.TraceAnnotation(tracereduce.WINDOW):
-            state, win = window(prog, state, ring, key, first, seconds)
-    finally:
-        jax.profiler.stop_trace()
-    hlo = prog.compiled.as_text()
-    op_scope, module = scopes.op_scopes(hlo), scopes.module_name(hlo)
-    try:
-        tl = load(str(TRACE_DIR))
-    finally:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    summary = reduce(tl, op_scope, module)
-    leads = step_leads(tl, module)
+    traced_c, traced_gc = Compiles(), Collections()
+
+    def counted():
+        with traced_c, traced_gc:
+            return window(prog, state, ring, key, first, seconds)
+
+    (state, win), tl = traced(counted)
+    summary = reduce(tl, step)
+    leads = step_leads(tl, step.module)
 
     tps = lambda w: w["steps"] * cell.tokens_per_step / w["seconds"]
     info = {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices)}
     run = {"tokens_per_s": tps(win), "steps": win["steps"],
            "chips": len(devices), "trace": summary,
-           "flops_per_token": flops.flops_per_token(cell.config,
-                                                    cell.traffic["seq"]),
+           "flops_per_token": cell.flops_per_token,
            "peak_flops": peaks.peaks(info["kind"])["bf16_flops"],
            "compiled_peak_bytes": prog.memory}
     metrics = {}
@@ -387,12 +455,12 @@ def measure(cell, seed: int, seconds: float, devices, out=None) -> dict:
         "failed": plain["failed"] + win["failed"],
         "metrics": metrics,
         "scope_ms": {k: per_step(sum(p[k] for p in summary["scope_s"]) / n)
-                     for k in NESTING},
+                     for k in scopes.SCOPES},
         "unscoped_ms": per_step(sum(summary["unscoped_s"]) / n),
         "module_busy_ms": per_step(sum(summary["module_busy_s"]) / n),
         "busy_ms": per_step(sum(summary["busy_s"]) / n),
         "window_s": summary["window_s"],
-        "module": module,
+        "module": step.module,
         "ops_without_module": sum(m is None for ms in tl.modules for m in ms),
         "step_lead_s": {"min": min(leads, default=None),
                         "median": (statistics.median(leads) if leads
@@ -400,11 +468,11 @@ def measure(cell, seed: int, seconds: float, devices, out=None) -> dict:
                         "max": max(leads, default=None),
                         "n": len(leads)},
         "idle_gaps": summary["idle_gaps"],
-        "device_ops": [[n, t, op_scope.get(n)]
+        "device_ops": [[n, t, step.op_scope.get(n)]
                        for n, t in summary["device_ops"]],
     }
     if out is not None:
-        result["timeline"] = _dump(tl, op_scope, pathlib.Path(out),
+        result["timeline"] = _dump(tl, step.op_scope, pathlib.Path(out),
                                    f"{cell.name}_{seed}")
     return result
 

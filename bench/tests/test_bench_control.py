@@ -28,11 +28,11 @@ def test_control_fails_and_program_passes(name, seed):
     key = program.base_key(seed)
     ring_np = program.make_ring(cell, seed)
     batches = program.batches_for_check(cell, ring_np, steps)
-    arch = reference.Arch.from_config(cell.config)
+    model = reference.Model.of(cell.arch, cell.config)
     optim = reference.Optim.from_traffic(cell.traffic)
     devices = jax.devices()[:1]
-    ref = reference.train(arch, optim, key, batches, devices)
-    ctl = reference.train(arch, optim, key, batches, devices,
+    ref = reference.train(model, optim, key, batches, devices)
+    ctl = reference.train(model, optim, key, batches, devices,
                           dtype=jnp.bfloat16, precision="default")
     ok, checks = check.verdict(check.numbers(ctl, ref), cell.limits)
     assert not ok, checks

@@ -62,7 +62,7 @@ import check, program, reference, jax.numpy as jnp
 cell = benchtiny.small("qwen3-4b.s4096b1.powersgd.1chip", 4)
 key = program.base_key({seed})
 batches = program.batches_for_check(cell, program.make_ring(cell, {seed}), 3)
-args = (reference.Arch.from_config(cell.config),
+args = (reference.Model.of(cell.arch, cell.config),
         reference.Optim.from_traffic(cell.traffic), key, batches, jax.devices())
 ref = reference.train(*args)
 ctl = reference.train(*args, dtype=jnp.bfloat16, precision="default")

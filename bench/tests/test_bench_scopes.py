@@ -1,6 +1,10 @@
-"""The scope reduction (``bench/scopetrace.py``): per-scope device time of
-the step module read from a trace, idle gaps placed by step and host span,
-and the readers of the per-scope metrics."""
+"""The scope reduction (``bench/scopetrace.py``): the step's scopes and
+their nesting read from its HLO, per-scope device time of the step module
+read from a trace, idle gaps placed by step and host span, and the readers
+of the per-scope metrics."""
+
+import re
+import textwrap
 
 import pytest
 
@@ -9,6 +13,7 @@ import benchtiny  # noqa: F401
 import cell as cell_lib
 import scopetrace
 import tracereduce
+from repro.core import scopes
 
 MS = 1_000_000  # ns
 
@@ -29,6 +34,28 @@ OPS = [("%while.1 = (f32[8]) while(...)", 0, 40, STEP_MODULE),
 OP_SCOPE = {"while.1": "loss_grad", "fusion.2": "loss_grad",
             "fusion.3": "loss_grad", "fusion.4": "ef_apply",
             "while.5": "compress", "all-reduce.6": "exchange"}
+WITHIN = {"compress": frozenset({"ef_apply"}),
+          "exchange": frozenset({"ef_apply", "compress"})}
+STEP = scopetrace.StepScopes(OP_SCOPE, WITHIN, STEP_MODULE)
+READERS = ("model.fwd_bwd_ms", "compress.ms", "ef_apply.ms", "exchange.ms")
+
+# the step's HLO as the compiled step gives it: the ops above with their
+# op_name paths (the end of fusion.3's is PATH, filled in by each test),
+# fusion.7 with no metadata
+HLO = """\
+HloModule jit_local_step, is_scheduled=true
+
+ENTRY %main.9 (a.9: f32[8]) -> f32[8] {
+  %a.9 = f32[8]{0} parameter(0)
+  %while.1 = f32[8]{0} while(%a.9), condition=%cond.1, body=%body.1, metadata={op_name="jit(local_step)/loss_grad/jvp()/while"}
+  %fusion.2 = f32[8]{0} fusion(%a.9), kind=kLoop, calls=%f.2, metadata={op_name="jit(local_step)/jvp(loss_grad)/dot_general"}
+  %fusion.3 = f32[8]{0} fusion(%a.9), kind=kLoop, calls=%f.3, metadata={op_name="jit(local_step)/transpose(jvp(loss_grad))/PATH"}
+  %fusion.4 = f32[8]{0} fusion(%a.9), kind=kLoop, calls=%f.4, metadata={op_name="jit(local_step)/ef_apply/add"}
+  %while.5 = f32[8]{0} while(%a.9), condition=%cond.5, body=%body.5, metadata={op_name="jit(local_step)/ef_apply/compress/while"}
+  %all-reduce.6 = f32[8]{0} all-reduce(%a.9), replica_groups={}, to_apply=%r.6, metadata={op_name="jit(local_step)/ef_apply/compress/exchange/psum"}
+  ROOT %fusion.7 = f32[8]{0} fusion(%a.9), kind=kLoop, calls=%f.7
+}
+"""
 
 
 def timeline():
@@ -45,21 +72,29 @@ def timeline():
         steps=[(0.0, 3)])
 
 
-def test_scope_times_count_nested_ops_once_innermost_first():
-    st = scopetrace.scope_times(timeline(), OP_SCOPE, STEP_MODULE)
+def test_step_scopes_read_the_nesting_from_the_hlo():
+    step = scopetrace.StepScopes.of(HLO.replace("PATH", "dot_general"))
+    assert step == STEP
+    assert scopetrace.StepScopes.of(re.sub(
+        r", metadata=\{[^}]*\}", "", HLO)) == \
+        scopetrace.StepScopes({}, {}, STEP_MODULE)
+
+
+def test_scope_times_count_nested_ops_once_in_every_enclosing_scope():
+    st = scopetrace.scope_times(timeline(), STEP)
     d0 = st["scope_s"][0]
     # the loop and its body count once: 40 ms, not 60
     assert d0["loss_grad"] == pytest.approx(40e-3)
-    assert d0["ef_apply"] == pytest.approx(10e-3)
-    # the exchange inside the compress loop goes to exchange
+    # the exchange inside the compress loop, and both inside ef_apply
     assert d0["exchange"] == pytest.approx(5e-3)
-    assert d0["compress"] == pytest.approx(15e-3)
+    assert d0["compress"] == pytest.approx(20e-3)
+    assert d0["ef_apply"] == pytest.approx(30e-3)
     # the other module's op of the same name is left out
     assert st["module_busy_s"][0] == pytest.approx(75e-3)
     assert st["unscoped_s"][0] == pytest.approx(5e-3)
     for per, un, busy in zip(st["scope_s"], st["unscoped_s"],
                              st["module_busy_s"]):
-        assert sum(per.values()) + un == pytest.approx(busy)
+        assert per["loss_grad"] + per["ef_apply"] + un == pytest.approx(busy)
     assert st["scope_s"][1] == pytest.approx(
         {"loss_grad": 20e-3, "ef_apply": 0.0, "compress": 0.0,
          "exchange": 0.0})
@@ -82,7 +117,7 @@ def test_reduce_adds_to_tracereduce_only():
     base = tracereduce.reduce(tl)
     plain = scopetrace.reduce(tl)
     assert "scope_s" not in plain
-    scoped = scopetrace.reduce(tl, OP_SCOPE, STEP_MODULE)
+    scoped = scopetrace.reduce(tl, STEP)
     for key in ("window_s", "busy_s", "collective_s", "exposed_s",
                 "device_ops"):
         assert plain[key] == scoped[key] == base[key]
@@ -96,7 +131,7 @@ def test_step_leads_pair_spans_with_module_runs():
 
 
 def test_scope_readers():
-    r = scopetrace.reduce(timeline(), OP_SCOPE, STEP_MODULE)
+    r = scopetrace.reduce(timeline(), STEP)
     run = {"trace": r, "steps": 2}
     read = lambda m: cell_lib.reader(m)(run)
     assert read("model.fwd_bwd_ms") == pytest.approx((40 + 20) / 2 / 2)
@@ -106,12 +141,73 @@ def test_scope_readers():
     for per in r["scope_s"]:
         per["exchange"] = 0.0
     assert read("exchange.ms") is None
+    # a step whose HLO maps no scope yields no scope number
     for trace in (None, scopetrace.reduce(timeline()),
-                  scopetrace.reduce(timeline(), {}, STEP_MODULE)):
+                  scopetrace.reduce(timeline(), scopetrace.StepScopes(
+                      {}, {}, STEP_MODULE))):
         run["trace"] = trace
-        for m in ("model.fwd_bwd_ms", "compress.ms", "ef_apply.ms",
-                  "exchange.ms"):
+        for m in READERS:
             assert read(m) is None
+
+
+NESTED_READER = """
+def read(run):
+    tr = run.get("trace")
+    if not tr or not tr.get("scope_s"):
+        return None
+    per = [d["attn"] for d in tr["scope_s"]]
+    return 1e3 * sum(per) / len(per) / run["steps"]
+"""
+
+
+def test_a_scope_nested_in_loss_grad_is_read_by_its_own_file(tmp_path,
+                                                              monkeypatch):
+    def readings(path):
+        step = scopetrace.StepScopes.of(HLO.replace("PATH", path))
+        run = {"trace": scopetrace.reduce(timeline(), step), "steps": 2}
+        return {m: cell_lib.reader(m)(run) for m in READERS + ("attn.ms",)}
+
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "attn.ms.py").write_text(
+        textwrap.dedent(NESTED_READER))
+    monkeypatch.setattr(cell_lib, "SEARCH", (tmp_path,) + cell_lib.SEARCH)
+    monkeypatch.setattr(scopes, "SCOPES", scopes.SCOPES + ("attn",))
+    before = readings("dot_general")
+    after = readings("transpose(jvp(attn))/dot_general")
+    assert before.pop("attn.ms") == 0.0
+    # fusion.3, 20-30 ms on device 0, none on device 1, over 2 steps
+    assert after.pop("attn.ms") == pytest.approx(10 / 2 / 2)
+    assert after == before
+    assert None not in before.values()
+
+
+def test_a_cached_step_without_scopes_is_compiled_again(capsys):
+    import jax
+
+    import program
+    from benchtiny import small
+
+    class Stripped:
+        def __init__(self, compiled):
+            self.compiled = compiled
+
+        def as_text(self):
+            return re.sub(r", metadata=\{[^}]*\}", "",
+                          self.compiled.as_text())
+
+    cell = small("qwen3-4b.s4096b1.powersgd.1chip")
+    prog = program.Program(cell, jax.devices()[:1])
+    key = program.base_key(3)
+    batch = prog.put_ring(program.make_ring(cell, 3))[0]
+    prog.compile(batch, key)
+    assert scopetrace.step_scopes(prog, batch, key).op_scope
+    assert "compiling it again" not in capsys.readouterr().err
+    prog.compiled = Stripped(prog.compiled)
+    step = scopetrace.step_scopes(prog, batch, key)
+    assert "compiling it again" in capsys.readouterr().err
+    assert not isinstance(prog.compiled, Stripped)
+    assert set(step.op_scope.values()) == set(scopes.SCOPES)
+    assert step.module == scopes.module_name(prog.compiled.as_text())
 
 
 def test_load_keeps_modules_and_steps(tmp_path):

@@ -1,6 +1,6 @@
-"""The yardstick's arithmetic: FLOPs per token, the table of peaks, the
-trace reduction, the check's worst-leaf rule, the traffic generator, and the
-harness's refusal to run without a TPU."""
+"""The yardstick's arithmetic: FLOPs per token (the decoder's), the table of
+peaks, the trace reduction, the check's worst-leaf rule, the traffic
+generator, and the harness's refusal to run without a TPU."""
 
 import json
 import os
@@ -16,7 +16,6 @@ from benchtiny import BENCH
 import cell as cell_lib
 import check
 import data
-import flops
 import peaks
 import reference
 import tracereduce
@@ -29,19 +28,21 @@ def config(name):
 
 def test_flops_per_token_qwen3():
     c = config("qwen3-4b-l4")
+    arch = cell_lib.arch_module(c)
     # per layer: q,o 2560x4096 each, k,v 2560x1024 each, SwiGLU 3x2560x9728
-    assert flops.matmul_weights_per_token(c) == 4 * 100_925_440 + 48_619_520
+    assert arch.matmul_weights_per_token(c) == 4 * 100_925_440 + 48_619_520
     # causal attention, fwd+bwd: 3 x 4 x (32x128) x 4097/2 per layer
-    assert flops.attention_flops_per_token(c, 4096) == 4 * 100_687_872
-    assert flops.flops_per_token(c, 4096) == 3_116_679_168
+    assert arch.attention_flops_per_token(c, 4096) == 4 * 100_687_872
+    assert arch.flops_per_token(c, 4096) == 3_116_679_168
 
 
 def test_flops_per_token_olmoe():
     c = config("olmoe-1b-7b-l1")
+    arch = cell_lib.arch_module(c)
     # attention 4x2048x2048, router 2048x64, top-8 of 3x2048x1024, head
-    assert flops.matmul_weights_per_token(c) == 67_239_936 + 12_877_824
-    assert flops.attention_flops_per_token(c, 4096) == 50_343_936
-    assert flops.flops_per_token(c, 4096) == 531_050_496
+    assert arch.matmul_weights_per_token(c) == 67_239_936 + 12_877_824
+    assert arch.attention_flops_per_token(c, 4096) == 50_343_936
+    assert arch.flops_per_token(c, 4096) == 531_050_496
 
 
 def test_peaks_known_and_unknown():
